@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 These handle shape padding (kernels need block-divisible dims), dtype
-plumbing, and the interpret-mode switch (CPU validation; TPU is the
-target).  Model code calls only these.
+plumbing, and the interpret-mode switch (``INTERPRET``: interpreted on
+the CPU backend, compiled on the TPU, refused elsewhere).  Model code
+calls only these.
 """
 from __future__ import annotations
 
@@ -15,8 +16,21 @@ from repro.kernels import flash_decode as _fd
 from repro.kernels import quant_matmul as _qm
 from repro.quant.ptq import QTensor
 
-# CPU containers run kernels in interpret mode; on TPU this is False.
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode(backend: str) -> bool:
+    """Whether the Pallas kernels run interpreted: on the CPU (tests,
+    reduced-width runs) they do, on the TPU they compile.  Any other
+    backend is refused rather than interpreted in silence."""
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"no Pallas kernel path for JAX backend {backend!r}: kernels "
+        f"compile for 'tpu' and run interpreted on 'cpu' only")
+
+
+INTERPRET = interpret_mode(jax.default_backend())
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import compiler_params
+
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 256
@@ -48,15 +50,15 @@ def _unpack_int4_tile(packed: jax.Array) -> jax.Array:
 
     Output row r reads packed row r//2 (a sublane repeat — no
     stack+reshape interleave tile in VMEM), then a parity-selected shift
-    sign-extends the right nibble: even rows ``(x << 4) >> 4`` (low
-    nibble), odd rows ``x >> 4`` (high nibble), both arithmetic on int8.
-    Operand values and ordering match the historical stack-based unpack
-    exactly, so downstream dots are bitwise-identical.
+    pair sign-extends the right nibble in int32 (Mosaic has no int8
+    shifts): even rows ``(x << 28) >> 28`` (low nibble), odd rows
+    ``(x << 24) >> 28`` (high nibble).  Values and order equal
+    ``quant.ptq.unpack_int4`` bit for bit (pinned in the tests).
     """
-    rep = jnp.repeat(packed, 2, axis=0)                   # (2R, C)
+    rep = jnp.repeat(packed.astype(jnp.int32), 2, axis=0)  # (2R, C)
     row = jax.lax.broadcasted_iota(jnp.int32, rep.shape, 0)
-    lshift = jnp.where(row % 2 == 0, 4, 0).astype(jnp.int8)
-    return ((rep << lshift) >> 4).astype(jnp.int8)
+    lshift = jnp.where(row % 2 == 0, 28, 24)
+    return ((rep << lshift) >> 28).astype(jnp.int8)
 
 
 def _mm_kernel_int8(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int):
@@ -161,8 +163,8 @@ def quant_matmul(x: jax.Array, q: jax.Array, scale: jax.Array,
                                    lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            compiler_params=compiler_params("parallel", "parallel",
+                                            "arbitrary"),
             interpret=interpret,
         )(x, x_scale.astype(jnp.float32), q,
           scale.reshape(1, N).astype(jnp.float32))
@@ -180,7 +182,6 @@ def quant_matmul(x: jax.Array, q: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x, q, scale.reshape(1, N).astype(jnp.float32))

@@ -15,6 +15,7 @@ import argparse
 from repro.config import get_arch
 from repro.core.environment import paper_env, tpu_env
 from repro.core.policy import get_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import ServingEngine
 from repro.serving.runtime import EngineExecutor, EpochRuntime
 
@@ -45,6 +46,7 @@ def main(argv=None):
     ap.add_argument("--s-max", type=int, default=64)
     ap.add_argument("--n-max", type=int, default=32)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     env_fn = tpu_env if args.tpu_env else paper_env

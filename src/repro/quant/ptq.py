@@ -22,6 +22,7 @@ fall out as the w-bits/16 ratio they predicted.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Tuple
 
@@ -95,13 +96,14 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     return ((rep << lshift) >> 4).astype(jnp.int8)
 
 
-def quantize(w: jax.Array, bits: int = 8, act_bits: int = 16) -> QTensor:
-    """Per-output-channel symmetric RTN quantization (reduction axis -2)."""
-    assert bits in (4, 8), bits
-    assert act_bits in (8, 16), act_bits
-    assert w.ndim >= 2, w.shape
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _quantize_values(w: jax.Array, qmax: jax.Array, bits: int):
+    """(q, scale) of :func:`quantize`, under ``jit`` so the f32 copy of
+    a stacked (L, K, N) leaf fuses away instead of materializing (3 GB
+    for bloom-3b's FFN weights).  ``qmax`` is an operand, not a
+    constant: XLA rewrites division by a constant into a reciprocal
+    multiply, which would move the scales off the eager values."""
     wf = w.astype(jnp.float32)
-    qmax = INT4_MAX if bits == 4 else INT8_MAX
     absmax = jnp.max(jnp.abs(wf), axis=-2, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
     q = jnp.clip(jnp.round(wf / scale), -qmax - 1, qmax).astype(jnp.int8)
@@ -111,6 +113,16 @@ def quantize(w: jax.Array, bits: int = 8, act_bits: int = 16) -> QTensor:
             pad[-2] = (0, 1)
             q = jnp.pad(q, pad)
         q = pack_int4(q)
+    return q, scale
+
+
+def quantize(w: jax.Array, bits: int = 8, act_bits: int = 16) -> QTensor:
+    """Per-output-channel symmetric RTN quantization (reduction axis -2)."""
+    assert bits in (4, 8), bits
+    assert act_bits in (8, 16), act_bits
+    assert w.ndim >= 2, w.shape
+    qmax = INT4_MAX if bits == 4 else INT8_MAX
+    q, scale = _quantize_values(w, jnp.float32(qmax), bits)
     return QTensor(q=q, scale=scale, bits=bits, shape=tuple(w.shape),
                    dtype=w.dtype, act_bits=act_bits)
 

@@ -39,7 +39,7 @@ bit-width is quantized once from the full-precision weights and kept in a
 small multi-precision cache (``params_for``), so swapping precision per
 epoch costs a dict lookup.  A precision is an int (weight bits) or a
 ``(weight_bits, act_bits)`` pair — W8A8 routes the dense matmuls through
-the int8-accumulation kernel tier on TPU.  On interpret backends every
+the int8-accumulation kernel tier on TPU.  On the CPU backend every
 family dequantizes at load (see ``params_for`` / DESIGN.md §3).
 """
 from __future__ import annotations
@@ -53,14 +53,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig, get_arch
+from repro.kernels.ops import INTERPRET as _INTERPRET
 from repro.models.api import Model, build_model
-from repro.quant.ptq import dequantize_tree, quantize_tree
+from repro.quant.ptq import QTensor, dequantize_tree, quantize_tree
 from repro.serving.kv_arena import (TRASH_PAGE, ZERO_PAGE, BlockTable,
                                     KVArena)
-
-# Interpret backends (no TPU) dequantize quantized trees at load and drop
-# activation-precision tags — see ServingEngine.params_for.
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 @dataclass
@@ -186,8 +183,23 @@ class ServingEngine:
         self._decode_kw = {"use_kernel": True} if use_kernel else {}
         if params is None:
             params = self.model.init(jax.random.key(seed))
-        self._raw_params = params            # full precision master copy
         self._params_cache: dict = {}        # weight_bits -> param tree
+        q = next((x for x in jax.tree.leaves(
+            params, is_leaf=lambda x: isinstance(x, QTensor))
+            if isinstance(x, QTensor)), None)
+        if q is None:
+            self._raw_params = params        # full precision master copy
+        else:
+            # an already-quantized tree is the ONE precision this engine
+            # serves: no fp master is kept (at full width it would not
+            # fit beside the quantized copy and the KV arena)
+            bits = self._canon_bits((q.bits, q.act_bits))
+            if quant_bits and self._canon_bits(quant_bits) != bits:
+                raise ValueError(f"params are quantized at {bits}, not "
+                                 f"quant_bits={quant_bits}")
+            self._raw_params = None
+            self._params_cache[bits] = self._servable(params)
+            quant_bits = bits
         self.default_bits = self._canon_bits(quant_bits)
         self.params = self.params_for(quant_bits)
         self.precisions_served: set = set()  # bit-widths generate() ran at
@@ -230,7 +242,7 @@ class ServingEngine:
 
         Accepts an int (weight bits; 0/16 both mean full precision) or a
         ``(weight_bits, act_bits)`` pair (a QuantMethod.serve_bits — W8A8
-        serves as ``(8, 8)``).  On interpret backends the activation tag
+        serves as ``(8, 8)``).  On the CPU backend the activation tag
         is canonicalized away — quantized trees are dequantized at load
         there (see ``params_for``), so (8, 8) and 8 would be the same
         tree and must share one cache entry."""
@@ -248,32 +260,41 @@ class ServingEngine:
         once and cached so the scheduler can swap the served method every
         epoch.  On TPU, dense/moe/vlm trees keep their QTensor leaves and
         serve through the Pallas kernel tiers (W8A16/W4A16, W8A8 when
-        tagged act_bits=8).  On interpret backends EVERY family
+        tagged act_bits=8).  On the CPU backend EVERY family
         dequantizes at load: int8 compute cannot beat the f32 BLAS there
         (measured, DESIGN.md §3), so quantized serving keeps fake-quant
         numerics but runs fp-speed XLA matmuls — quantization pays in
         bytes and on TPU, never as an interpret-mode slowdown."""
         bits = self._canon_bits(bits)
         if bits not in self._params_cache:
+            if self._raw_params is None:
+                raise ValueError(
+                    f"no precision {bits}: built from a quantized tree, "
+                    f"the engine has no full-precision master")
             if bits == 0:
                 p = self._raw_params
             else:
                 w, a = bits if isinstance(bits, tuple) else (bits, 16)
-                p = quantize_tree(self._raw_params, w, act_bits=a)
-                if self.cfg.family not in ("dense", "moe", "vlm") \
-                        or _INTERPRET:
-                    # recurrent/encdec matmuls don't route through
-                    # common.mm; interpret backends serve dequantized
-                    p = dequantize_tree(p)
+                p = self._servable(
+                    quantize_tree(self._raw_params, w, act_bits=a))
             self._params_cache[bits] = p
         return self._params_cache[bits]
+
+    def _servable(self, p):
+        """A quantized tree as this engine serves it: QTensor leaves for
+        the transformer families on the TPU; dequantized at load for the
+        families whose matmuls bypass ``common.mm`` (recurrent, encdec)
+        and on the CPU backend."""
+        if self.cfg.family not in ("dense", "moe", "vlm") or _INTERPRET:
+            return dequantize_tree(p)
+        return p
 
     def decode_tier(self, bits=None) -> str:
         """The Pallas decode-attention tier ``use_kernel=True`` serving
         at ``bits`` (engine default when None) routes to — ``"kv8"`` /
         ``"fused"`` / ``"flash"``, see ``kernels.ops.decode_kernel_tier``.
-        Interpret backends dequantize quantized trees at load, so they
-        report ``"flash"`` even for int8 methods."""
+        The CPU backend dequantizes quantized trees at load, so there it
+        reports ``"flash"`` even for int8 methods."""
         from repro.kernels import ops as kops
         params = self.params_for(self.default_bits if bits is None
                                  else bits)

@@ -105,3 +105,12 @@ def test_flash_decode_matches_xla_gqa_attention():
     got = ops.flash_decode(q[:, 0], k, v, n_valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_interpret_mode_only_on_cpu():
+    """Kernels interpret on the CPU, compile on the TPU, and refuse any
+    other backend instead of interpreting there in silence."""
+    assert ops.interpret_mode("cpu") is True
+    assert ops.interpret_mode("tpu") is False
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode("gpu")

@@ -366,11 +366,12 @@ def test_for_engines_rejects_layer_or_dtype_mismatch():
 
 def test_for_engines_pads_tails_to_cohort_max():
     """Cohorts with different head geometry share one pool: pages carry
-    the elementwise-max tail, each engine uses its leading corner."""
+    the elementwise-max tail, head dim rounded up to 128 lanes, and each
+    engine uses its leading corner."""
     a = _fake_engine(shape=(1, 1, 32, 2, 8))
     b = _fake_engine(shape=(1, 1, 32, 4, 4))
     arena = KVArena.for_engines([a, b], block_tokens=16, shrink=1.0)
-    assert arena.buffers()["k"].shape[3:] == (4, 8)
+    assert arena.buffers()["k"].shape[3:] == (4, 128)
     # 2 engines x batch 2 x (32/16 blocks) = 8 allocatable pages
     assert arena.total_pages == 8
     half = KVArena.for_engines([a, b], block_tokens=16, shrink=0.5)

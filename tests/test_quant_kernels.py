@@ -98,6 +98,20 @@ def test_unpack_int4_bitwise_matches_stack(shape):
     assert np.array_equal(got[..., :shape[-2], :], np.asarray(q))
 
 
+@pytest.mark.parametrize("shape", [(8, 16), (16, 128), (64, 256)])
+def test_unpack_int4_tile_bitwise_matches_ptq(shape):
+    """The kernel's int32-shift unpack equals ``ptq.unpack_int4`` bit for
+    bit over every nibble value."""
+    from repro.kernels.quant_matmul import _unpack_int4_tile
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.integers(-8, 8, size=shape), jnp.int8)
+    packed = pack_int4(q)
+    got = np.asarray(_unpack_int4_tile(packed))
+    assert got.dtype == np.int8
+    assert np.array_equal(got, np.asarray(unpack_int4(packed)))
+    assert np.array_equal(got, np.asarray(q))
+
+
 def test_quantize_rowwise_roundtrip():
     """|x - q * s| <= s/2 elementwise (symmetric RTN never clips)."""
     rng = np.random.default_rng(1)

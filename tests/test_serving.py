@@ -186,3 +186,23 @@ def test_engine_serves_decided_precisions_in_one_run():
     assert len(m.served_by_method) >= 2          # adaptive method mix
     assert len(eng.precisions_served) >= 2       # distinct weight bits
     assert set(eng.precisions_served) <= set(eng._params_cache)
+
+
+def test_engine_from_quantized_tree_serves_only_that_precision():
+    """A tree quantized ahead of time is served as is, with no fp master
+    kept: same tokens as an engine that quantizes its fp master, and a
+    request for any other precision is refused."""
+    from repro.quant.ptq import quantize_tree
+    base = ServingEngine(reduced_cfg("bloom-3b"), batch_capacity=2,
+                         s_max=16, n_max=6, quant_bits=8)
+    eng = ServingEngine(base.cfg, params=quantize_tree(base._raw_params, 8),
+                        batch_capacity=2, s_max=16, n_max=6)
+    assert eng._raw_params is None and eng.default_bits == 8
+    prompts = [[5, 6, 7], [9, 10, 11, 12]]
+    assert_same_generation(base.generate(prompts, n_tokens=[4, 6]),
+                           eng.generate(prompts, n_tokens=[4, 6]))
+    with pytest.raises(ValueError, match="no full-precision master"):
+        eng.params_for(0)
+    with pytest.raises(ValueError, match="quantized at"):
+        ServingEngine(base.cfg, params=quantize_tree(base._raw_params, 8),
+                      quant_bits=4)
