@@ -8,11 +8,27 @@ and their aliases are gone; drive ``EpochRuntime`` directly.
 
 Per-epoch accounting lives in ``traces`` so executor-equivalence tests can
 compare scheduling decisions epoch by epoch, not just aggregates.
+
+``span`` marks a stretch of the serving path's host work in the JAX
+profiler's trace, on the same clock as the device's operations.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
+
+SPAN_PREFIX = "repro:"
+
+
+def span(name: str, **int_args: int) -> TraceAnnotation:
+    """A host span ``repro:<name>`` in the profiler's trace.  A dotted
+    name is the engine's part of one of its calls (``segment.launch``,
+    ``segment.lease_topup`` inside it).  Arguments come back as the
+    event's stats; pass integers only.  Without a profiler session it
+    records nothing and costs about a microsecond."""
+    return TraceAnnotation(SPAN_PREFIX + name, **int_args)
 
 
 def percentile(xs: Sequence[float], q: float) -> float:

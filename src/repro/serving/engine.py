@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig, get_arch
+from repro.core.metrics import span
 from repro.kernels.ops import INTERPRET as _INTERPRET
 from repro.models.api import Model, build_model
 from repro.quant.ptq import QTensor, dequantize_tree, quantize_tree
@@ -761,42 +762,43 @@ class ServingEngine:
         ``prefixes`` seeds per-row forced-replay tokens (one entry per
         prompt, ``None`` = fresh row) for preemption resume — see
         ``_decode_chunk_fn``."""
-        params, bits, batch, caps_j, caps, _ = self._prepare(
-            prompts, n_tokens, quant_bits)
-        cur, cache = self._prefill(params, batch)
-        B = self.batch_capacity
-        if prefixes is None:       # keep the one-put-at-start invariant
-            forced = jnp.zeros((B, self.n_max), jnp.int32)
-            nf = jnp.zeros((B,), jnp.int32)
-        else:
-            forced, nf = jax.device_put(self._forced_buffers(prefixes))
-        if arena is None:
-            return DecodeState(
-                cache=cache, cur=cur,
-                out=jnp.zeros((B, self.n_max), jnp.int32),
-                lengths=jnp.zeros((B,), jnp.int32),
-                done=jnp.zeros((B,), bool),
-                caps=caps_j, t=jnp.int32(0), bits=bits, caps_host=caps,
-                forced=forced, n_forced=nf)
-        assert self.paged_capable, self.cfg.arch_id
-        bt = arena.block_tokens
-        assert self.cache_len % bt == 0, (self.cache_len, bt)
-        nb = self.cache_len // bt
-        table = BlockTable(B, nb, n_pages=arena.n_pages)
-        ids = np.full((B * nb,), TRASH_PAGE, np.int32)
-        lease_end = np.zeros((B,), np.int32)
-        lease_last = np.zeros((B,), np.int32)
-        for b in range(B):
-            if caps[b] > 0:
-                # cap-aware lease: prompt blocks + first write block now
-                # (blocks past it stay TRASH until a segment-boundary
-                # top-up), instead of the historical full-span alloc(nb)
-                blocks, row, le, ll = self._lease_row(arena, 0, caps[b])
-                leases = arena.alloc(len(blocks))
-                row[blocks] = leases
-                table.set_row(b, row)
-                ids[b * nb + np.asarray(blocks)] = leases
-                lease_end[b], lease_last[b] = le, ll
+        with span("prefill.prepare"):
+            params, bits, batch, caps_j, caps, _ = self._prepare(
+                prompts, n_tokens, quant_bits)
+            cur, cache = self._prefill(params, batch)
+            B = self.batch_capacity
+            if prefixes is None:       # keep the one-put-at-start invariant
+                forced = jnp.zeros((B, self.n_max), jnp.int32)
+                nf = jnp.zeros((B,), jnp.int32)
+            else:
+                forced, nf = jax.device_put(self._forced_buffers(prefixes))
+            if arena is None:
+                return DecodeState(
+                    cache=cache, cur=cur,
+                    out=jnp.zeros((B, self.n_max), jnp.int32),
+                    lengths=jnp.zeros((B,), jnp.int32),
+                    done=jnp.zeros((B,), bool),
+                    caps=caps_j, t=jnp.int32(0), bits=bits, caps_host=caps,
+                    forced=forced, n_forced=nf)
+            assert self.paged_capable, self.cfg.arch_id
+            bt = arena.block_tokens
+            assert self.cache_len % bt == 0, (self.cache_len, bt)
+            nb = self.cache_len // bt
+            table = BlockTable(B, nb, n_pages=arena.n_pages)
+            ids = np.full((B * nb,), TRASH_PAGE, np.int32)
+            lease_end = np.zeros((B,), np.int32)
+            lease_last = np.zeros((B,), np.int32)
+            for b in range(B):
+                if caps[b] > 0:
+                    # cap-aware lease: prompt blocks + first write block now
+                    # (blocks past it stay TRASH until a segment-boundary
+                    # top-up), instead of the historical full-span alloc(nb)
+                    blocks, row, le, ll = self._lease_row(arena, 0, caps[b])
+                    leases = arena.alloc(len(blocks))
+                    row[blocks] = leases
+                    table.set_row(b, row)
+                    ids[b * nb + np.asarray(blocks)] = leases
+                    lease_end[b], lease_last[b] = le, ll
         pages = self._page_scatter(arena.buffers(), cache,
                                    jax.device_put(ids))
         arena.set_buffers(pages)
@@ -819,26 +821,32 @@ class ServingEngine:
         advances through the paged segment loop — the arena page buffers
         are checked out, carried through the while-loop, and checked
         back in."""
-        params = self.params_for(state.bits)
-        t_end = jnp.minimum(state.t + jnp.int32(k), jnp.int32(self.n_max))
-        if isinstance(state, PagedDecodeState):
-            # boundary top-up: lease every block this segment can write
-            # BEFORE launching it (one host-side remap + one table
-            # re-ship; the jitted segment never allocates)
-            self._extend_leases(state, k)
-            pages, cur, out, lengths, done, t = self._decode_chunk_paged(
-                params, state.arena.buffers(), state.table.device,
-                state.cur, state.out, state.lengths, state.done,
-                state.caps, state.t, t_end, state.forced, state.n_forced)
-            state.arena.set_buffers(pages)
-            return dataclasses.replace(state, cur=cur, out=out,
-                                       lengths=lengths, done=done, t=t)
-        cache, cur, out, lengths, done, t = self._decode_chunk(
-            params, state.cache, state.cur, state.out, state.lengths,
-            state.done, state.caps, state.t, t_end, state.forced,
-            state.n_forced)
-        return dataclasses.replace(state, cache=cache, cur=cur, out=out,
-                                   lengths=lengths, done=done, t=t)
+        with span("segment.launch"):
+            params = self.params_for(state.bits)
+            t_end = jnp.minimum(state.t + jnp.int32(k),
+                                jnp.int32(self.n_max))
+            if isinstance(state, PagedDecodeState):
+                # boundary top-up: lease every block this segment can
+                # write BEFORE launching it (one host-side remap + one
+                # table re-ship; the jitted segment never allocates)
+                with span("segment.lease_topup"):
+                    self._extend_leases(state, k)
+                pages, cur, out, lengths, done, t = \
+                    self._decode_chunk_paged(
+                        params, state.arena.buffers(), state.table.device,
+                        state.cur, state.out, state.lengths, state.done,
+                        state.caps, state.t, t_end, state.forced,
+                        state.n_forced)
+                state.arena.set_buffers(pages)
+                return dataclasses.replace(state, cur=cur, out=out,
+                                           lengths=lengths, done=done, t=t)
+            cache, cur, out, lengths, done, t = self._decode_chunk(
+                params, state.cache, state.cur, state.out, state.lengths,
+                state.done, state.caps, state.t, t_end, state.forced,
+                state.n_forced)
+            return dataclasses.replace(state, cache=cache, cur=cur,
+                                       out=out, lengths=lengths, done=done,
+                                       t=t)
 
     def release_slots(self, state: PagedDecodeState,
                       slots: Sequence[int]) -> PagedDecodeState:
@@ -873,11 +881,13 @@ class ServingEngine:
         at production shapes ``out`` is the dominant transfer; ``out``
         comes back as None."""
         if not with_tokens:
-            lengths, done, t = jax.device_get(
-                (state.lengths, state.done, state.t))
+            with span("poll.fetch"):
+                lengths, done, t = jax.device_get(
+                    (state.lengths, state.done, state.t))
             return None, lengths, done, int(t)
-        out, lengths, done, t = jax.device_get(
-            (state.out, state.lengths, state.done, state.t))
+        with span("poll.fetch"):
+            out, lengths, done, t = jax.device_get(
+                (state.out, state.lengths, state.done, state.t))
         return out, lengths, done, int(t)
 
     def exhausted(self, lengths, done, caps_host, t) -> bool:
@@ -948,57 +958,60 @@ class ServingEngine:
         row's ``t + n`` span stays TRASH until the segment-boundary
         top-up leases it (DESIGN.md §2.3).
         """
-        B = self.batch_capacity
-        params = self.params_for(state.bits)
-        toks = np.zeros((B, self.s_max), np.int32)
-        new_caps = np.zeros((B,), np.int32)
-        refill = np.zeros((B,), bool)
-        cap_lim = min(self.n_max, self.headroom(t_now))
-        if cap_max is not None:
-            cap_lim = min(cap_lim, max(0, int(cap_max)))
-        if not slots or cap_lim <= 0:
-            return state
-        for slot, p, n in zip(slots, prompts, n_tokens):
-            p = list(p)[-self.s_max:]
-            if p:
-                toks[slot, -len(p):] = p
-            new_caps[slot] = min(int(n), cap_lim)
-            refill[slot] = True
-        toks_j, caps_j, refill_j = jax.device_put((toks, new_caps, refill))
-        new_cur, new_cache = self._prefill(params, self._as_batch(toks_j))
-        caps_host = np.where(refill, new_caps, state.caps_host)
-        # Forced-replay splice (preemption resume): refilled rows take
-        # their resume prefix (or reset to no-replay); live rows keep
-        # theirs.  Outside the jitted merges — it's a few KB — and the
-        # no-resume path skips the extra transfer entirely.
-        if prefixes is None:
-            forced = jnp.where(refill_j[:, None], 0, state.forced)
-            n_forced = jnp.where(refill_j, 0, state.n_forced)
-        else:
-            forced_j, nf_j = jax.device_put(
-                self._forced_buffers(prefixes, slots=slots))
-            forced = jnp.where(refill_j[:, None], forced_j, state.forced)
-            n_forced = jnp.where(refill_j, nf_j, state.n_forced)
-        if isinstance(state, PagedDecodeState):
-            arena = state.arena
-            bt = arena.block_tokens
-            nb = self.cache_len // bt
-            ids = np.full((B * nb,), TRASH_PAGE, np.int32)
-            for slot in slots:
-                arena.free(state.table.row_leases(slot))  # stale leases
-                # cap-aware lease: prompt blocks + the first write block
-                # (scattered so its gap-tail positions read as the
-                # slab's zeros); the junk gap maps to ZERO, everything
-                # past the first write block stays TRASH until the
-                # segment-boundary top-up reaches it
-                blocks, row, le, ll = self._lease_row(
-                    arena, t_now, new_caps[slot])
-                leases = arena.alloc(len(blocks))
-                row[blocks] = leases
-                state.table.set_row(slot, row)
-                ids[slot * nb + np.asarray(blocks)] = leases
-                state.lease_end[slot] = le
-                state.lease_last[slot] = ll
+        with span("prefill.prepare"):
+            B = self.batch_capacity
+            params = self.params_for(state.bits)
+            toks = np.zeros((B, self.s_max), np.int32)
+            new_caps = np.zeros((B,), np.int32)
+            refill = np.zeros((B,), bool)
+            cap_lim = min(self.n_max, self.headroom(t_now))
+            if cap_max is not None:
+                cap_lim = min(cap_lim, max(0, int(cap_max)))
+            if not slots or cap_lim <= 0:
+                return state
+            for slot, p, n in zip(slots, prompts, n_tokens):
+                p = list(p)[-self.s_max:]
+                if p:
+                    toks[slot, -len(p):] = p
+                new_caps[slot] = min(int(n), cap_lim)
+                refill[slot] = True
+            toks_j, caps_j, refill_j = jax.device_put((toks, new_caps, refill))
+            new_cur, new_cache = self._prefill(params, self._as_batch(toks_j))
+            caps_host = np.where(refill, new_caps, state.caps_host)
+            # Forced-replay splice (preemption resume): refilled rows take
+            # their resume prefix (or reset to no-replay); live rows keep
+            # theirs.  Outside the jitted merges — it's a few KB — and the
+            # no-resume path skips the extra transfer entirely.
+            if prefixes is None:
+                forced = jnp.where(refill_j[:, None], 0, state.forced)
+                n_forced = jnp.where(refill_j, 0, state.n_forced)
+            else:
+                forced_j, nf_j = jax.device_put(
+                    self._forced_buffers(prefixes, slots=slots))
+                forced = jnp.where(refill_j[:, None], forced_j, state.forced)
+                n_forced = jnp.where(refill_j, nf_j, state.n_forced)
+            paged = isinstance(state, PagedDecodeState)
+            if paged:
+                arena = state.arena
+                bt = arena.block_tokens
+                nb = self.cache_len // bt
+                ids = np.full((B * nb,), TRASH_PAGE, np.int32)
+                for slot in slots:
+                    arena.free(state.table.row_leases(slot))  # stale leases
+                    # cap-aware lease: prompt blocks + the first write block
+                    # (scattered so its gap-tail positions read as the
+                    # slab's zeros); the junk gap maps to ZERO, everything
+                    # past the first write block stays TRASH until the
+                    # segment-boundary top-up reaches it
+                    blocks, row, le, ll = self._lease_row(
+                        arena, t_now, new_caps[slot])
+                    leases = arena.alloc(len(blocks))
+                    row[blocks] = leases
+                    state.table.set_row(slot, row)
+                    ids[slot * nb + np.asarray(blocks)] = leases
+                    state.lease_end[slot] = le
+                    state.lease_last[slot] = ll
+        if paged:
             pages = self._page_scatter(arena.buffers(), new_cache,
                                        jax.device_put(ids))
             arena.set_buffers(pages)

@@ -48,12 +48,13 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
+from collections import Counter
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from repro.core.environment import EdgeEnv
-from repro.core.metrics import EpochMetrics, EpochTrace
+from repro.core.metrics import EpochMetrics, EpochTrace, span
 from repro.core.multi import MultiLLMEnv, multi_feasible
 from repro.core.policy import (Decision, DrainStallError,
                                InfeasibleDecisionError,
@@ -414,10 +415,22 @@ class ContinuousExecutor:
         return pool["capacity"] - len(pool["resident"]) \
             - len(pool["pending"])
 
+    def refusal(self, mid: Optional[str], r: Request) -> Optional[str]:
+        """The first of the data plane's gates that refuses ``r`` a place
+        in pool ``mid`` at this boundary — ``"no_pool"`` (no such hosted
+        model), ``"slots"``, then, on planes that have them,
+        ``"headroom"`` and ``"pages"`` — or None when it fits.  Slot
+        structure only: P1 feasibility is the runtime's job, via
+        ``policy.validate``."""
+        if mid not in self._pools:
+            return "no_pool"
+        if self.free_slots(mid) <= 0:
+            return "slots"
+        return None
+
     def accepts(self, mid: Optional[str], r: Request) -> bool:
-        """Slot-structure gate only (P1 feasibility is the runtime's
-        job, via ``policy.validate``)."""
-        return mid in self._pools and self.free_slots(mid) > 0
+        """No gate of the data plane refuses ``r`` a place in ``mid``."""
+        return self.refusal(mid, r) is None
 
     def place(self, mid: Optional[str], r: Request,
               resume: Optional[dict] = None,
@@ -531,11 +544,11 @@ class ContinuousExecutor:
     def arena_blocked(self, mid: Optional[str], r: Request) -> bool:
         """True when admitting ``r`` into ``mid`` is refused by the
         node's PHYSICAL KV budget (the paged arena) even though the pool
-        has free slots — the case where preemption must look at OTHER
-        pools' residents, since any cohort's released pages free the
-        shared arena.  Data planes without a page pool are never
-        arena-blocked."""
-        return False
+        has a free slot and the headroom — the case where preemption
+        must look at OTHER pools' residents, since any cohort's released
+        pages free the shared arena.  Data planes without a page pool
+        are never arena-blocked."""
+        return self.refusal(mid, r) == "pages"
 
     def method_name(self, mid: Optional[str], env_r: EdgeEnv,
                     rid: Optional[int] = None) -> str:
@@ -774,40 +787,28 @@ class EngineContinuousExecutor(ContinuousExecutor):
                 total += pool["engine"].lease_commitment(pool["state"])
         return total
 
-    def accepts(self, mid, r) -> bool:
-        if not super().accepts(mid, r):
-            return False
+    def refusal(self, mid, r) -> Optional[str]:
+        why = super().refusal(mid, r)
+        if why is not None:
+            return why
         pool = self._pools[mid]
-        if pool.get("paged"):
-            # per-block admission: can this request's cap-aware pages be
-            # reserved, on top of boundary admissions already pending
-            # AND the top-up entitlement resident rows still hold?  (The
-            # multi_feasible oracle stays authoritative for the paper's
-            # constraints — this gates physical KV.)
-            need = self._pages_needed(mid, r)
-            budget = self.arena.free_pages - self._pending_pages \
-                - self._outstanding_pages()
-            if budget < need:
-                return False
-        if pool["state"] is None:
-            return True     # fresh cohort: full n_max headroom of its own
-        return self.node_headroom(mid) >= min(r.n, pool["engine"].n_max)
-
-    def arena_blocked(self, mid, r) -> bool:
-        """``accepts`` refused ``r`` on the shared PAGE budget while the
-        pool itself had room (free slot + headroom): the signal that
-        cross-pool preemption can help — evicting any cohort's resident
-        returns its pages to the node arena (DESIGN.md §2.3/§2.4)."""
-        pool = self._pools[mid]
-        if not pool.get("paged") or self.free_slots(mid) <= 0:
-            return False
+        # a live cohort shares one decode position: a row joining at
+        # step t can emit at most n_max - t tokens (a fresh cohort has
+        # the full n_max of its own)
         if pool["state"] is not None and \
                 self.node_headroom(mid) < min(r.n, pool["engine"].n_max):
-            return False    # headroom-bound, not memory-bound
-        need = self._pages_needed(mid, r)
-        budget = self.arena.free_pages - self._pending_pages \
-            - self._outstanding_pages()
-        return budget < need
+            return "headroom"
+        # per-block admission: can this request's cap-aware pages be
+        # reserved, on top of boundary admissions already pending AND
+        # the top-up entitlement resident rows still hold?  (The
+        # multi_feasible oracle stays authoritative for the paper's
+        # constraints — this gates physical KV.)
+        if pool.get("paged"):
+            budget = self.arena.free_pages - self._pending_pages \
+                - self._outstanding_pages()
+            if budget < self._pages_needed(mid, r):
+                return "pages"
+        return None
 
     def place(self, mid, r, resume=None, quant=None):
         # reserve the candidate's cap-aware pages against this boundary
@@ -859,52 +860,53 @@ class EngineContinuousExecutor(ContinuousExecutor):
             else:
                 take = []
             if take:
-                slots = [s for s, _, _, _ in take]
-                reqs = [r for _, r, _, _ in take]
-                prompts, caps, prefixes = [], [], []
-                for slot, r, resume, _ in take:
-                    if resume is None:
-                        # same rng draw order as the historical batched
-                        # synth call — fresh admissions are bit-stable
-                        p, c = eng.synth_prompts([r], self.rng)
-                        prompts.append(p[0])
-                        caps.append(c[0])
-                        prefixes.append(None)
+                with span("refill", rows=len(take)):
+                    slots = [s for s, _, _, _ in take]
+                    reqs = [r for _, r, _, _ in take]
+                    prompts, caps, prefixes = [], [], []
+                    for slot, r, resume, _ in take:
+                        if resume is None:
+                            # same rng draw order as the historical batched
+                            # synth call — fresh admissions are bit-stable
+                            p, c = eng.synth_prompts([r], self.rng)
+                            prompts.append(p[0])
+                            caps.append(c[0])
+                            prefixes.append(None)
+                        else:
+                            # resume: re-prefill the ORIGINAL prompt and
+                            # replay the delivered prefix bit-exactly via
+                            # the engine's forced-prefix mechanism
+                            prompts.append(resume["prompt"])
+                            caps.append(min(r.n, eng.n_max))
+                            prefixes.append(resume["prefix"])
+                        pool["prompts"][slot] = prompts[-1]
+                    ff = max((len(p) for p in prefixes if p), default=0)
+                    if all(p is None for p in prefixes):
+                        prefixes = None
+                    if pool["state"] is None:
+                        pool["state"] = eng.start_chunked(
+                            prompts, caps, quant_bits=self._cohort_bits(pool),
+                            arena=self.arena if pool["paged"] else None,
+                            prefixes=prefixes)
+                        pool["t"] = 0
                     else:
-                        # resume: re-prefill the ORIGINAL prompt and
-                        # replay the delivered prefix bit-exactly via
-                        # the engine's forced-prefix mechanism
-                        prompts.append(resume["prompt"])
-                        caps.append(min(r.n, eng.n_max))
-                        prefixes.append(resume["prefix"])
-                    pool["prompts"][slot] = prompts[-1]
-                ff = max((len(p) for p in prefixes if p), default=0)
-                if all(p is None for p in prefixes):
-                    prefixes = None
-                if pool["state"] is None:
-                    pool["state"] = eng.start_chunked(
-                        prompts, caps, quant_bits=self._cohort_bits(pool),
-                        arena=self.arena if pool["paged"] else None,
-                        prefixes=prefixes)
-                    pool["t"] = 0
-                else:
-                    pool["state"] = eng.refill_chunked(
-                        pool["state"], slots, prompts, caps,
-                        t_now=pool["t"], cap_max=clamps[mid],
-                        prefixes=prefixes)
-                pool["resident"].update(zip(slots, reqs))
-                if ff:
-                    # Eager resume replay: the forced-prefix steps
-                    # re-derive tokens the user ALREADY HAS, so they are
-                    # burned here at the admitting boundary instead of
-                    # consuming the segment grid's k-token budget — the
-                    # deadline gate judges a resume on its REMAINING
-                    # tokens (runtime._hopeless) and this is what makes
-                    # that promise true on the engine path.  Token
-                    # streams are unchanged (chunk-size invariance).
-                    pool["state"] = eng.generate_chunked(pool["state"],
-                                                         ff)
-                    pool["t"] = min(pool["t"] + ff, eng.n_max)
+                        pool["state"] = eng.refill_chunked(
+                            pool["state"], slots, prompts, caps,
+                            t_now=pool["t"], cap_max=clamps[mid],
+                            prefixes=prefixes)
+                    pool["resident"].update(zip(slots, reqs))
+                    if ff:
+                        # Eager resume replay: the forced-prefix steps
+                        # re-derive tokens the user ALREADY HAS, so they are
+                        # burned here at the admitting boundary instead of
+                        # consuming the segment grid's k-token budget — the
+                        # deadline gate judges a resume on its REMAINING
+                        # tokens (runtime._hopeless) and this is what makes
+                        # that promise true on the engine path.  Token
+                        # streams are unchanged (chunk-size invariance).
+                        pool["state"] = eng.generate_chunked(pool["state"],
+                                                             ff)
+                        pool["t"] = min(pool["t"] + ff, eng.n_max)
         # landed reservations became real leases; re-reserve for pendings
         # still HELD for a later sub-batch (conservatively at the pool's
         # current cohort step)
@@ -925,24 +927,25 @@ class EngineContinuousExecutor(ContinuousExecutor):
                 pool["state"], with_tokens=self.collect_tokens)
             pool["t"] = t
             caps_h = pool["state"].caps_host
-            freed = []
-            for slot, r in list(pool["resident"].items()):
-                if done[slot] or lengths[slot] >= caps_h[slot]:
-                    finished.append((mid, r, int(lengths[slot])))
-                    if self.collect_tokens:
-                        self.outputs[r.rid] = \
-                            np.array(out[slot][:lengths[slot]])
-                    del pool["resident"][slot]
-                    pool["prompts"].pop(slot, None)
-                    freed.append(slot)
-            if pool["paged"] and freed:
-                # release-on-completion: the freed pages are allocatable
-                # by ANY cohort at the next admission boundary
-                pool["state"] = eng.release_slots(pool["state"], freed)
-            if not pool["resident"]:
-                if pool["paged"]:
-                    eng.release_all(pool["state"])
-                pool["state"], pool["t"] = None, 0   # cohort drained
+            with span("release"):
+                freed = []
+                for slot, r in list(pool["resident"].items()):
+                    if done[slot] or lengths[slot] >= caps_h[slot]:
+                        finished.append((mid, r, int(lengths[slot])))
+                        if self.collect_tokens:
+                            self.outputs[r.rid] = \
+                                np.array(out[slot][:lengths[slot]])
+                        del pool["resident"][slot]
+                        pool["prompts"].pop(slot, None)
+                        freed.append(slot)
+                if pool["paged"] and freed:
+                    # release-on-completion: the freed pages are
+                    # allocatable by ANY cohort at the next boundary
+                    pool["state"] = eng.release_slots(pool["state"], freed)
+                if not pool["resident"]:
+                    if pool["paged"]:
+                        eng.release_all(pool["state"])
+                    pool["state"], pool["t"] = None, 0   # cohort drained
         return finished, occupied / capacity if capacity else 0.0
 
     def preempt(self, mid, rid):
@@ -1359,7 +1362,8 @@ class ContinuousRuntime(EpochRuntime):
             policy.install_swap_costs(measure_swap_cost(eng, iters=1))
 
     def _try_admit(self, queue: List[Request], trace: EpochTrace,
-                   degraded: bool = False) -> List[Request]:
+                   degraded: bool = False
+                   ) -> Tuple[List[Request], Dict[int, str]]:
         """Admit queued requests into free slots — first-fit in
         ``_admission_order`` — each gated by the policy's own
         feasibility oracle on the joint resident-plus-candidate batch —
@@ -1379,8 +1383,16 @@ class ContinuousRuntime(EpochRuntime):
         inside its backoff window (``SpillRecord.not_before``) is
         skipped this boundary; when a spilled request IS re-admitted,
         its resume payload rides along so the executor restores the
-        spilled progress."""
+        spilled progress.
+
+        Returns the admitted requests and, by rid, the first gate that
+        refused each of the others: ``"quarantined"``, ``"backoff"``,
+        ``"deadline"`` (``_hopeless``), the executor's ``refusal``
+        (``"no_pool"``, ``"slots"``, ``"headroom"``, ``"pages"``), or
+        ``"infeasible"`` (``validate()`` false, after the split
+        fallback)."""
         admitted: List[Request] = []
+        refused: Dict[int, str] = {}
         cexec = self.cexec
         batches = {m: cexec.resident(m) for m in cexec.pool_ids()}
         # methods the ACTIVE cohorts are being served with (a drained
@@ -1390,14 +1402,17 @@ class ContinuousRuntime(EpochRuntime):
         fresh_sel: Dict[Optional[str], Optional[QuantMethod]] = {}
         for r in self._admission_order(queue):
             mid = r.model_id
-            if mid in self._quarantined:
-                continue
             rec = self._spills.get(r.rid)
-            if rec is not None and self._boundary < rec.not_before:
-                continue               # resume backoff not yet elapsed
-            if self.deadline_gated and self._hopeless(r, rec):
-                continue               # can't finish by deadline anyway
-            if mid not in batches or not cexec.accepts(mid, r):
+            if mid in self._quarantined:
+                why = "quarantined"
+            elif rec is not None and self._boundary < rec.not_before:
+                why = "backoff"        # resume backoff not yet elapsed
+            elif self.deadline_gated and self._hopeless(r, rec):
+                why = "deadline"       # can't finish by deadline anyway
+            else:
+                why = cexec.refusal(mid, r)
+            if why is not None:
+                refused[r.rid] = why
                 continue
             starting = not batches[mid]
             if starting:
@@ -1447,9 +1462,10 @@ class ContinuousRuntime(EpochRuntime):
                 admitted.append(r)
             else:
                 batches[mid].pop()
+                refused[r.rid] = "infeasible"
         if admitted:
             self._assert_jointly_feasible(batches, quants)
-        return admitted
+        return admitted, refused
 
     def _try_preempt(self, queue: List[Request], trace: EpochTrace,
                      m: EpochMetrics, counting: bool
@@ -1700,79 +1716,93 @@ class ContinuousRuntime(EpochRuntime):
             trace = EpochTrace(epoch=e, arrived=0, dropped=0,
                                selected_rids=[], counted=counting)
             for j in range(n_seg):
-                t_seg = e * T_E + j * dt
-                self._tnow = t_seg
-                now = t_seg + dt
-                # requests that arrived during the previous SEGMENT join
-                # here — the epoch loop's boundary rule, at segment grain
-                arrivals = gen.within(t_seg - dt, t_seg) if (e or j) else []
-                if tag_arrivals is not None:
-                    arrivals = tag_arrivals(arrivals)
-                trace.arrived += len(arrivals)
-                if counting:
-                    m.arrived += len(arrivals)
-                queue.extend(arrivals)
+                with span("boundary", n=self._boundary):
+                    t_seg = e * T_E + j * dt
+                    self._tnow = t_seg
+                    now = t_seg + dt
+                    # requests that arrived during the previous SEGMENT join
+                    # here — the epoch loop's boundary rule, at segment grain
+                    arrivals = gen.within(t_seg - dt, t_seg) \
+                        if (e or j) else []
+                    if tag_arrivals is not None:
+                        arrivals = tag_arrivals(arrivals)
+                    trace.arrived += len(arrivals)
+                    if counting:
+                        m.arrived += len(arrivals)
+                    queue.extend(arrivals)
 
-                queue, n_dropped = self._age_and_drop(queue, t_seg)
-                trace.dropped += n_dropped
-                if counting:
-                    m.dropped += n_dropped
-
-                # graceful degradation: advance the hysteresis, and in
-                # degraded mode shed the controller's lowest-priority
-                # queued work before admission considers it
-                degraded = False
-                if self.degradation is not None:
-                    degraded = self.degradation.observe(len(queue))
-                    if degraded:
+                    with span("admit") as sp:
+                        queue, n_dropped = self._age_and_drop(queue, t_seg)
+                        trace.dropped += n_dropped
                         if counting:
-                            m.degraded_segments += 1
-                        queue = self._shed_queue(queue, m, trace,
-                                                 counting)
-                        if not self._was_degraded:
-                            # rising edge: LIVE cohorts degrade too,
-                            # not just the ones that start from now on
-                            self._requant_live(m, trace, counting,
-                                               queue)
-                    elif self._was_degraded and self._requant_prior:
-                        # falling edge: restore the pre-flip methods so
-                        # high-accuracy queued work stops starving
-                        self._requant_restore(m, trace, counting)
-                    self._was_degraded = degraded
+                            m.dropped += n_dropped
 
-                admitted = self._try_admit(queue, trace, degraded)
-                if self.preemption:
-                    got = {r.rid for r in admitted}
-                    rest = [r for r in queue if r.rid not in got]
-                    preempt_admits, requeued = self._try_preempt(
-                        rest, trace, m, counting)
-                    admitted = admitted + preempt_admits
-                if admitted:
-                    got = {r.rid for r in admitted}
-                    queue = [r for r in queue if r.rid not in got]
-                    trace.selected_rids.extend(r.rid for r in admitted)
-                    if j > 0:
-                        trace.admitted_mid_epoch += len(admitted)
-                        if counting:
-                            m.admitted_mid_epoch += len(admitted)
-                    for r in admitted:
-                        if r.rid in self._spills and counting:
-                            m.resumed += 1
-                        self._first_token.setdefault(r.rid, now)
-                if self.preemption and requeued:
-                    queue.extend(requeued)
-
-                finished, occ, wall = self._step_guarded(m, trace,
+                        # graceful degradation: advance the hysteresis, and in
+                        # degraded mode shed the controller's lowest-priority
+                        # queued work before admission considers it
+                        degraded = False
+                        if self.degradation is not None:
+                            degraded = self.degradation.observe(len(queue))
+                            if degraded:
+                                if counting:
+                                    m.degraded_segments += 1
+                                queue = self._shed_queue(queue, m, trace,
                                                          counting)
-                self._boundary += 1
-                trace.wall_s += wall
-                trace.segments += 1
-                trace.occupancy.append(occ)
-                self._record_blocks(counting, m, trace)
-                if counting:
-                    m.segments += 1
-                self._record_finished(finished, counting, m, trace,
-                                      now=now)
+                                if not self._was_degraded:
+                                    # rising edge: LIVE cohorts degrade too,
+                                    # not just the ones that start from now on
+                                    self._requant_live(m, trace, counting,
+                                                       queue)
+                            elif self._was_degraded and self._requant_prior:
+                                # falling edge: restore the pre-flip methods so
+                                # high-accuracy queued work stops starving
+                                self._requant_restore(m, trace, counting)
+                            self._was_degraded = degraded
+
+                        admitted, refused = self._try_admit(queue, trace,
+                                                            degraded)
+                        if self.preemption:
+                            got = {r.rid for r in admitted}
+                            rest = [r for r in queue if r.rid not in got]
+                            preempt_admits, requeued = self._try_preempt(
+                                rest, trace, m, counting)
+                            admitted = admitted + preempt_admits
+                            for r in preempt_admits:
+                                del refused[r.rid]
+                        # the boundary's admission outcomes ride on its
+                        # span: each queued request once, under
+                        # "admitted" or the first gate that refused it
+                        outcomes = Counter(refused.values())
+                        if admitted:
+                            outcomes["admitted"] = len(admitted)
+                        sp.set_metadata(**outcomes)
+                    if admitted:
+                        got = {r.rid for r in admitted}
+                        queue = [r for r in queue if r.rid not in got]
+                        trace.selected_rids.extend(r.rid for r in admitted)
+                        if j > 0:
+                            trace.admitted_mid_epoch += len(admitted)
+                            if counting:
+                                m.admitted_mid_epoch += len(admitted)
+                        for r in admitted:
+                            if r.rid in self._spills and counting:
+                                m.resumed += 1
+                            self._first_token.setdefault(r.rid, now)
+                    if self.preemption and requeued:
+                        queue.extend(requeued)
+
+                    with span("step"):
+                        finished, occ, wall = self._step_guarded(m, trace,
+                                                                 counting)
+                    self._boundary += 1
+                    trace.wall_s += wall
+                    trace.segments += 1
+                    trace.occupancy.append(occ)
+                    self._record_blocks(counting, m, trace)
+                    if counting:
+                        m.segments += 1
+                    self._record_finished(finished, counting, m, trace,
+                                          now=now)
 
             if counting:
                 m.batch_sizes.append(len(trace.selected_rids))
@@ -1787,7 +1817,9 @@ class ContinuousRuntime(EpochRuntime):
         for _ in range(self.drain_limit):
             if self.cexec.idle():
                 break
-            finished, occ, wall = self._step_guarded(m, trace, counting)
+            with span("boundary", n=self._boundary), span("step"):
+                finished, occ, wall = self._step_guarded(m, trace,
+                                                         counting)
             self._boundary += 1
             now += dt
             trace.wall_s += wall

@@ -219,11 +219,13 @@ def test_arena_blocked_flags_cross_pool_memory_pressure():
     assert cexec.free_slots("bloom-3b") > 0
     assert not cexec.accepts("bloom-3b", rc)      # page budget refuses
     assert cexec.arena_blocked("bloom-3b", rc)    # ...and says why
+    assert cexec.refusal("bloom-3b", rc) == "pages"
     # evicting the OTHER pool's resident returns its pages to the node
     payload = cexec.preempt("bloom-7b1", residents[0].rid)
     assert payload["remaining"] > 0
     assert cexec.accepts("bloom-3b", rc)
     assert not cexec.arena_blocked("bloom-3b", rc)
+    assert cexec.refusal("bloom-3b", rc) is None
 
 
 def test_cross_pool_preemption_run_conserves():
