@@ -202,7 +202,8 @@ def check_fused_decode(cfg, B, W, block_tokens, seed: int = 0) -> dict:
         want, _, _ = _oracle(common.decode_attention, p_deq, cfg,
                              x[:, None], ck, cv, pos)
         want_p, _ = _oracle(common.decode_attention_paged, p_deq, cfg,
-                            x[:, None], pages, table, pos)
+                            x[:, None], {n: a[None] for n, a in
+                                         pages.items()}, 0, table, pos)
         o, _, _ = ops.flash_decode_fused(x, p["wq"], p["wk"], p["wv"],
                                          p["wo"], ck, cv, pos,
                                          rope_theta=cfg.rope_theta)
@@ -306,8 +307,9 @@ def served_tree_report(engine, arena, bits) -> dict:
 def kernel_vs_xla_logits(engine, arena, bits, seed: int = 0) -> dict:
     """First decode step of one cohort through the paged decode step with
     and without ``use_kernel``, on the same weights and pages.  Both
-    steps write the same slot before attending to it, so the second
-    sees what the first saw; the pages are donated, as in serving."""
+    steps attend over the pages plus the same token and write it at the
+    same slot, so the second sees what the first saw; the pages are
+    donated, as in serving."""
     rng = np.random.default_rng(seed)
     B = engine.batch_capacity
     prompts = [rng.integers(1, engine.cfg.vocab, size=engine.s_max).tolist()

@@ -386,92 +386,124 @@ def decode_attention_cache(p: Params, cfg: ModelConfig, x: jax.Array,
         {"k": ck, "v": cv, "ks": ks, "vs": vs}
 
 
-def decode_attention_paged(p: Params, cfg: ModelConfig, x: jax.Array,
-                           pages: Dict[str, jax.Array], table: jax.Array,
-                           pos: jax.Array, use_kernel: bool = False
-                           ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode step over a PAGED cache (DESIGN.md §2.3).
+def paged_write_slot(table: jax.Array, pos: jax.Array, bt: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Where one decode step's token lands in the arena, the same for
+    every layer: page ``table[b, pos // bt]`` (B,) at offset ``pos % bt``
+    (scalar)."""
+    B = table.shape[0]
+    blk = (pos // bt).astype(jnp.int32)
+    page = jnp.take_along_axis(table, jnp.broadcast_to(blk, (B,))[:, None],
+                               axis=1)[:, 0]
+    return page, (pos % bt).astype(jnp.int32)
 
-    pages: one layer's slice of the node-wide arena — {"k","v"} of shape
-    (P, block_tokens, nkv', dh') (+ {"ks","vs"} (P, block_tokens, nkv')
-    scales when cfg.kv_bits == 8); table: (B, n_b) int32 mapping logical
-    block j of row b to its physical page.  Page tails may be LARGER
-    than this model's (nkv, dh) — the node pool provisions the max over
-    hosted cohorts — so every write targets and every read slices the
-    leading (nkv, dh) corner; the padding is zero-initialized and never
-    observed.  The token is written at page ``table[b, pos // bt]``
-    offset ``pos % bt``; attention then gathers the row's logical blocks
-    back into the (B, n_b*bt, nkv, dh) view — bitwise the contiguous
-    cache when the pages hold the same values, which is what makes the
-    paged engine path bit-identical to the slab path (rows whose table
-    points at the shared trash page are dead and never emit again, so
-    their garbage is unobservable).  ``use_kernel`` routes the read
-    through ``flash_decode_paged`` (no gather; TPU path, fp cache only).
+
+def write_paged_tokens(pages: Dict[str, jax.Array],
+                       tokens: Dict[str, jax.Array], page: jax.Array,
+                       off: jax.Array) -> Dict[str, jax.Array]:
+    """Write one decode step's tokens of every layer into the arena: one
+    scatter per leaf of ``tokens[name]`` (L, B, nkv[, dh]) at
+    ``[:, page[b], off]``, into the leading (nkv, dh) corner of the page
+    tail.  On a donated arena it updates the buffers in place.  Rows on
+    ``TRASH_PAGE`` may scatter to the same index; that page is
+    don't-care."""
+    out = {}
+    for name, pleaf in pages.items():
+        tok = tokens[name]
+        corner = tuple(slice(0, d) for d in tok.shape[2:])
+        out[name] = pleaf.at[(slice(None), page, off) + corner].set(
+            tok.astype(pleaf.dtype))
+    return out
+
+
+def decode_attention_paged(p: Params, cfg: ModelConfig, x: jax.Array,
+                           pages: Dict[str, jax.Array], layer: jax.Array,
+                           table: jax.Array, pos: jax.Array,
+                           use_kernel: bool = False
+                           ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode step of layer ``layer`` over a PAGED cache
+    (DESIGN.md §2.3).
+
+    pages: the node-wide arena, read only — {"k","v"} of shape
+    (L, P, block_tokens, nkv', dh') (+ {"ks","vs"} (L, P, block_tokens,
+    nkv') scales when cfg.kv_bits == 8); table: (B, n_b) int32 mapping
+    logical block j of row b to its physical page.  Page tails may be
+    LARGER than this model's (nkv, dh) — the node pool provisions the
+    max over hosted cohorts — so every read slices the leading (nkv, dh)
+    corner; the padding is zero-initialized and never observed.
+
+    Returns (out (B, 1, D), tokens): this step's keys and values in the
+    page dtype, {"k","v"} (B, nkv, dh) (+ {"ks","vs"} (B, nkv)).  The
+    caller writes them at ``paged_write_slot`` after the layer scan
+    (``write_paged_tokens``), so the arena is never restacked per layer.
+    Attention gathers the row's logical blocks from the pre-write pages
+    into the (B, n_b*bt, nkv, dh) view and puts this step's token at
+    position ``pos``: the view the write-then-gather order gives, since
+    a live row's write page is its own lease — bitwise the contiguous
+    cache, which is what makes the paged engine path bit-identical to
+    the slab path (rows whose table points at the shared trash page are
+    dead and never emit again).  ``use_kernel`` routes the read through
+    the fused tier (which attends over the pre-write pages plus the
+    current token) or ``flash_decode_paged`` (TPU path, fp cache only),
+    whose operand is this layer's slice with the token written in.
     """
     B = x.shape[0]
     nkv, dh = cfg.n_kv_heads, cfg.d_head
-    bt = pages["k"].shape[1]
     n_b = table.shape[1]
-    W = n_b * bt
-    # physical page holding this step's write block, per row
-    blk = (pos // bt).astype(jnp.int32)
-    page = jnp.take_along_axis(table, jnp.broadcast_to(blk, (B,))[:, None],
-                               axis=1)[:, 0]                     # (B,)
-    off = (pos % bt).astype(jnp.int32)
+    W = n_b * pages["k"].shape[2]
+    dt = pages["k"].dtype
+
+    def layer_slice(pleaf):
+        return pleaf[layer, ..., :nkv, :dh]
+
     if use_kernel and cfg.kv_bits != 8:
         from repro.kernels import ops as kops
         if kops.fusable_decode(p, cfg):
             o, k1f, v1f = kops.flash_decode_fused_paged(
                 x[:, 0], p["wq"], p["wk"], p["wv"], p["wo"],
-                pages["k"][..., :nkv, :dh], pages["v"][..., :nkv, :dh],
+                layer_slice(pages["k"]), layer_slice(pages["v"]),
                 table, pos, rope_theta=cfg.rope_theta)
-            pk = pages["k"].at[page, off, :nkv, :dh].set(
-                k1f.astype(pages["k"].dtype))
-            pv = pages["v"].at[page, off, :nkv, :dh].set(
-                v1f.astype(pages["v"].dtype))
             return constrain(o[:, None], "batch", None, None), \
-                {"k": pk, "v": pv}
+                {"k": k1f.astype(dt), "v": v1f.astype(dt)}
     positions = jnp.full((B, 1), pos, dtype=jnp.int32)
     q, k1, v1 = qkv_proj(p, cfg, x, positions)
-
-    def gather(pleaf):
-        """Row-major view of a row's logical blocks, tail-sliced to this
-        model's geometry: (B, W, nkv[, dh])."""
-        g = pleaf[table]                     # (B, n_b, bt, *tail')
-        g = g[..., :nkv, :dh] if g.ndim == 5 else g[..., :nkv]
-        return g.reshape((B, W) + g.shape[3:])
-
     if cfg.kv_bits == 8:
         k1q, k1s = quantize_kv(k1)
         v1q, v1s = quantize_kv(v1)
-        pk = pages["k"].at[page, off, :nkv, :dh].set(k1q[:, 0])
-        pv = pages["v"].at[page, off, :nkv, :dh].set(v1q[:, 0])
-        pks = pages["ks"].at[page, off, :nkv].set(k1s[:, 0])
-        pvs = pages["vs"].at[page, off, :nkv].set(v1s[:, 0])
-        new_pages = {"k": pk, "v": pv, "ks": pks, "vs": pvs}
-        dt = x.dtype
-        kd = dequantize_kv(gather(pk), gather(pks), dt)
-        vd = dequantize_kv(gather(pv), gather(pvs), dt)
+        tokens = {"k": k1q[:, 0], "v": v1q[:, 0],
+                  "ks": k1s[:, 0], "vs": v1s[:, 0]}
     else:
-        pk = pages["k"].at[page, off, :nkv, :dh].set(
-            k1[:, 0].astype(pages["k"].dtype))
-        pv = pages["v"].at[page, off, :nkv, :dh].set(
-            v1[:, 0].astype(pages["v"].dtype))
-        new_pages = {"k": pk, "v": pv}
-        kd = vd = None
+        tokens = {"k": k1[:, 0].astype(dt), "v": v1[:, 0].astype(dt)}
     n_valid = jnp.minimum(pos + 1, W)
     if use_kernel and cfg.kv_bits != 8:
         from repro.kernels import ops as kops
-        out = kops.flash_decode_paged(q[:, 0], pk[..., :nkv, :dh],
-                                      pv[..., :nkv, :dh], table, n_valid)
+        page, off = paged_write_slot(table, pos, pages["k"].shape[2])
+        pk, pv = (layer_slice(pages[n]).at[page, off].set(tokens[n])
+                  for n in ("k", "v"))
+        out = kops.flash_decode_paged(q[:, 0], pk, pv, table, n_valid)
         out = out[:, None]
     else:
-        if kd is None:
-            kd, vd = gather(pk), gather(pv)
+        def view(name):
+            """Row-major view of a row's logical blocks with this step's
+            token at ``pos``, tail-sliced to this model's geometry:
+            (B, W, nkv[, dh]).  A select, not an update-slice, so the
+            splice fuses into the attention's reads."""
+            g = pages[name][layer, table]        # (B, n_b, bt, *tail')
+            g = g[..., :nkv, :dh] if g.ndim == 5 else g[..., :nkv]
+            g = g.reshape((B, W) + g.shape[3:])
+            hit = (jnp.arange(W) == pos).reshape((1, W)
+                                                 + (1,) * (g.ndim - 2))
+            return jnp.where(hit, tokens[name][:, None], g)
+
+        if cfg.kv_bits == 8:
+            kd = dequantize_kv(view("k"), view("ks"), x.dtype)
+            vd = dequantize_kv(view("v"), view("vs"), x.dtype)
+        else:
+            kd, vd = view("k"), view("v")
         mask = (jnp.arange(W) < n_valid)[None, None, None, None, :]
         out = gqa_attention(q, kd, vd, mask)
     out = mm(out.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"])
-    return constrain(out, "batch", None, None), new_pages
+    return constrain(out, "batch", None, None), tokens
 
 
 def prefill_cache_from_kv(k: jax.Array, v: jax.Array, W: int

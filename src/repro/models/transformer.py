@@ -230,25 +230,32 @@ def decode_step_paged(cfg: ModelConfig, params: Params, pages, table,
     (L, P, block_tokens, nkv, dh) (+ scale leaves when kv_bits == 8);
     ``table``: (B, n_b) int32 block table, shared by every layer (one
     allocation covers all L layers of a row's block).  Scans layers over
-    axis 0 of both params and pages; the table is a scan-invariant
-    closure.  Returns (logits, new_pages)."""
+    axis 0 of the params and a layer index; the arena and the table are
+    scan-invariant operands that each layer only reads.  The scan
+    returns each layer's new token, and one scatter per leaf writes all
+    L of them after it, so no op of the step touches more of the arena
+    than a gather reads.  Returns (logits, new_pages)."""
     x = common.maybe_dequant(params["embed"])[tokens]
     x = constrain(x, "batch", None, None)
+    n_layers = pages["k"].shape[0]
 
     def body(x, inputs):
-        lp, layer_pages = inputs
+        lp, layer = inputs
         h = common.apply_norm(cfg.norm, lp["norm1"], x)
-        att, layer_pages = common.decode_attention_paged(
-            lp["attn"], cfg, h, layer_pages, table, pos, use_kernel)
+        att, new_tokens = common.decode_attention_paged(
+            lp["attn"], cfg, h, pages, layer, table, pos, use_kernel)
         x = x + att
         h = common.apply_norm(cfg.norm, lp["norm2"], x)
         if cfg.is_moe:
             out, _ = common.moe_apply(lp["moe"], cfg, h)
         else:
             out = common.ffn_apply(lp["ffn"], cfg, h)
-        return x + out, layer_pages
+        return x + out, new_tokens
 
-    x, new_pages = jax.lax.scan(body, x, (params["layers"], pages))
+    x, new_tokens = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    page, off = common.paged_write_slot(table, pos, pages["k"].shape[2])
+    new_pages = common.write_paged_tokens(pages, new_tokens, page, off)
     x = common.apply_norm(cfg.norm, params["final_norm"], x)
     logits = _unembed(cfg, params, x)[:, 0]
     return logits, new_pages

@@ -84,7 +84,10 @@ class BlockTable:
     @property
     def device(self) -> jax.Array:
         if self._device is None:
-            self._device = jax.device_put(self.host)
+            # ship a copy: on the CPU backend device_put may alias an
+            # aligned host array, and the row edits below rewrite
+            # ``host`` in place while a dispatched segment still reads it
+            self._device = jax.device_put(self.host.copy())
         return self._device
 
     def _check(self, pages: np.ndarray) -> None:

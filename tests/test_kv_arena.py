@@ -143,6 +143,23 @@ def test_block_table_rows_and_leases():
     assert tbl.device is not dev0                   # mutation re-ships
 
 
+def test_block_table_device_mirror_is_a_snapshot():
+    """Row edits after a re-ship never reach a device table already
+    handed to a computation, even where the host array is aligned so
+    that the CPU backend could take it without a copy."""
+    tbl = BlockTable(batch=8, n_blocks=3)
+    raw = np.empty(tbl.host.size + 16, np.int32)
+    start = (-raw.ctypes.data % 64) // 4           # 64-byte aligned view
+    tbl.host = raw[start:start + tbl.host.size].reshape(tbl.host.shape)
+    tbl.host[:] = TRASH_PAGE
+    tbl.set_row(0, [5, 6, 7])
+    dev = tbl.device
+    tbl.clear_row(0)
+    tbl.extend_row(1, 0, [8, 9])
+    np.testing.assert_array_equal(np.asarray(dev)[:2],
+                                  [[5, 6, 7], [TRASH_PAGE] * 3])
+
+
 def test_arena_free_rejects_double_free_and_reserved_pages():
     """The free-path guards are REAL ``ArenaError`` raises, not asserts
     — CI re-runs this file under ``python -O`` (which strips asserts)
@@ -528,6 +545,107 @@ def test_paged_refill_matches_slab_refill(hetero_node):
     assert arena.free_pages == free0
     for leaf in arena.buffers().values():       # ZERO page never written
         assert not np.asarray(leaf[:, ZERO_PAGE]).any()
+
+
+def _paged_view(leaf, table_row, n_pos, tail):
+    """A row's logical positions [0, n_pos) read back from an arena leaf
+    through its block-table row, sliced to the engine's tail."""
+    L, _, bt = leaf.shape[:3]
+    v = np.asarray(leaf)[:, table_row].reshape((L, -1) + leaf.shape[3:])
+    return v[(slice(None), slice(0, n_pos))
+             + tuple(slice(0, d) for d in tail)]
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8])
+def test_paged_segment_matches_slab_segment_at_block_edges(kv_bits):
+    """Blocks of 4 tokens put decode writes at every offset, a block's
+    first and last included; rows 1 and 2 run past their caps and write
+    to TRASH_PAGE in the same step; row 1 is then refilled mid-cohort
+    over a ZERO_PAGE gap.  The paged segment's tokens, lengths, done
+    and t equal the slab segment's, and every row that still writes to
+    its own pages holds the slab's keys and values (and scales, for
+    int8 KV) at every position written so far."""
+    cfg = reduced_cfg("bloom-3b").scaled(n_layers=3, kv_bits=kv_bits)
+    eng = ServingEngine(cfg, batch_capacity=4, s_max=16, n_max=16)
+    arena = KVArena.for_engines([eng], block_tokens=4)
+    prompts, caps = [[1, 2, 3], [4, 5], [6], [7, 8, 9, 10]], [16, 2, 3, 16]
+
+    def drive(paged):
+        st = eng.start_chunked(prompts, caps, arena=arena if paged else None)
+        st = eng.generate_chunked(st, 5)        # steps 0-4, positions 16-20
+        _, lengths, _, t = eng.poll_chunked(st)
+        np.testing.assert_array_equal(lengths[1:3], caps[1:3])
+        if paged:                               # step 4 wrote rows 1, 2
+            assert (st.table.host[1:3, 20 // 4] == TRASH_PAGE).all()
+        st = eng.refill_chunked(st, [1], [[9, 9, 9]], [8], t_now=t)
+        if paged:                               # gap [16, 21) -> block 4
+            assert st.table.host[1, 16 // 4] == ZERO_PAGE
+        for _ in range(3):
+            st = eng.generate_chunked(st, 2)
+        return st, eng.poll_chunked(st)
+
+    slab, want = drive(paged=False)
+    paged, got = drive(paged=True)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    n_pos = eng.s_max + got[3]
+    for name, leaf in arena.buffers().items():
+        cache = np.asarray(slab.cache[name])
+        for row in (0, 1, 3):
+            np.testing.assert_array_equal(
+                _paged_view(leaf, paged.table.host[row], n_pos,
+                            cache.shape[3:]),
+                cache[:, row, :n_pos])
+        assert not np.asarray(leaf[:, ZERO_PAGE]).any()
+    eng.release_all(paged)
+    assert arena.free_pages == arena.total_pages
+
+
+def _jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr nested in its equations (loop bodies,
+    branches, calls), depth first."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _jaxprs(sub)
+
+
+@pytest.mark.parametrize("kv_bits,use_kernel",
+                         [(16, False), (8, False), (16, True)])
+def test_paged_decode_layer_scan_reads_the_arena_in_place(kv_bits,
+                                                          use_kernel):
+    """The layer scan of the paged decode segment takes the arena as a
+    scan-invariant operand: no scan carries, scans over or stacks a value
+    of an arena leaf's shape (a stacked ys rewrites the whole arena every
+    step), so the step's tokens reach the arena only through the write
+    after the scan."""
+    cfg = reduced_cfg("bloom-3b").scaled(n_layers=3, kv_bits=kv_bits)
+    eng = ServingEngine(cfg, batch_capacity=2, s_max=16, n_max=16,
+                        use_kernel=use_kernel)
+    pages = KVArena.for_engines([eng], block_tokens=4).buffers()
+    B, n = eng.batch_capacity, eng.n_max
+
+    def i32(*shape):
+        return jnp.zeros(shape, jnp.int32)
+
+    closed = jax.make_jaxpr(eng._decode_chunk_paged_fn)(
+        eng.params, pages, i32(B, eng.cache_len // 4), i32(B), i32(B, n),
+        i32(B), jnp.zeros((B,), bool), i32(B), i32(), i32(), i32(B, n),
+        i32(B))
+    arena_shapes = {leaf.shape for leaf in pages.values()}
+    scans = [e for j in _jaxprs(closed.jaxpr) for e in j.eqns
+             if e.primitive.name == "scan"]
+    consts = set()
+    for eqn in scans:
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        scanned = eqn.invars[nc:] + eqn.outvars
+        assert not {v.aval.shape for v in scanned} & arena_shapes
+        consts |= {v.aval.shape for v in eqn.invars[:nc]}
+    assert arena_shapes <= consts               # the layer scan reads it
 
 
 # -- continuous executor: per-block admission + lease lifecycle --------------

@@ -10,9 +10,13 @@ Widths: bloom-3b projections (d_model 2560, d_ff 10240) for the matmul
 tiers, bloom-3b attention (32 heads, d_head 80 padded to 128) for
 flash-decode, one bloom-7b1 attention layer (d_model 4096, 32 heads,
 d_head 128) for the fused tier; a cohort of 8 rows, 512 + 128 cache
-slots, 16-token pages.
+slots, 16-token pages.  One whole program besides: the paged decode
+segment of bloom-3b at W8A16, compiled to check what it does to the KV
+arena.
 """
 from __future__ import annotations
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +113,67 @@ def test_flash_decode_fused_paged_compiles(one_chip, a8):
              *_fused_weights(), ((P, BT, NKV, DH), bf16),
              ((P, BT, NKV, DH), bf16), ((B, NB), i32), ((B,), i32),
              ((B,), i32), ((1, DH // 2), f32), ((1, DH // 2), f32))
+
+
+def _shapes(text):
+    """{instruction name: (opcode, result dims, operand names)} of every
+    array-valued instruction in compiled HLO text."""
+    out = {}
+    inst = r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)"
+    for m in re.finditer(inst, text):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        out[m.group(1)] = (m.group(3), dims,
+                           re.findall(r"%([\w.\-]+)", m.group(4)))
+    return out
+
+
+def test_paged_decode_segment_writes_tokens_not_the_arena(one_chip,
+                                                          monkeypatch):
+    """The paged decode segment of bloom-3b at W8A16 (30 layers, 8 rows
+    of 512 + 512 positions in 514 pages of 16 tokens, 32 heads of 80 in
+    128-lane page tails) holds no op that copies, broadcasts, slices or
+    rewrites the arena or a layer of it: every instruction with a
+    dimension of 514 pages is a pass-through or an in-place
+    dynamic-update-slice of one token window (one page, one offset)."""
+    from repro.config import get_arch
+    from repro.kernels import ops
+    from repro.models.api import build_model
+    from repro.quant.ptq import MATMUL_KEYS, quantize_tree
+    from repro.serving.engine import ServingEngine
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    cfg = get_arch("bloom-3b")
+    s_max = n_max = 512
+    nb = (s_max + n_max) // BT
+    n_pages = 2 + B * nb
+    init = build_model(cfg).init
+    eng = ServingEngine(cfg, params=jax.eval_shape(init, jax.random.key(0)),
+                        batch_capacity=B, s_max=s_max, n_max=n_max)
+    w8 = jax.eval_shape(     # int8 block matrices, bf16 tied embedding
+        lambda k: quantize_tree(init(k), 8, keys=MATMUL_KEYS - {"embed"}),
+        jax.random.key(0))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    arena = (cfg.n_layers, n_pages, BT, cfg.n_kv_heads, 128)
+    args = (jax.tree.map(lambda a: sds(a.shape, a.dtype), w8),
+            {"k": sds(arena, bf16), "v": sds(arena, bf16)},
+            sds((B, nb), i32), sds((B,), i32), sds((B, n_max), i32),
+            sds((B,), i32), sds((B,), jnp.bool_), sds((B,), i32),
+            sds((), i32), sds((), i32), sds((B, n_max), i32),
+            sds((B,), i32))
+    text = jax.jit(eng._decode_chunk_paged_fn,
+                   donate_argnums=(1, 3, 4, 5, 6)).lower(*args) \
+        .compile().as_text()
+    assert "tpu_custom_call" in text            # the W8A16 matmuls
+    shapes = _shapes(text)
+    writes = 0
+    for name, (op, dims, operands) in shapes.items():
+        if n_pages not in dims or op in ("parameter", "get-tuple-element",
+                                         "bitcast"):
+            continue
+        assert op == "dynamic-update-slice", (name, op, dims)
+        window = shapes[operands[1]][1]
+        assert dims == arena and window[1:3] == (1, 1), (name, window)
+        writes += 1
+    assert writes == 2                          # one per leaf, k and v
